@@ -32,8 +32,8 @@ import numpy as np
 from repro.analysis import Finding
 
 HOST_CALLBACK_PRIMITIVES = {
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "outside_call", "host_callback_call", "infeed", "outfeed",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "outside_call", "host_callback_call", "infeed", "outfeed",
 }
 # benign converts: iota/bool masks and scalar bookkeeping promote freely
 DEFAULT_BIG_ELEMS = 8192

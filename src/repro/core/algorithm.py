@@ -28,7 +28,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from repro.core.blocks import Block, CostModel, graph_of
-from repro.core.delay import total_delay
+from repro.core.delay import LayeredTotalDelay
 from repro.core.network import DeviceNetwork
 from repro.core.scoring import score
 
@@ -79,6 +79,7 @@ class ResourceAwareAssigner:
         place = np.full(B, -1, dtype=int)
         mem_used = np.zeros(V)
         comp_used = np.zeros(V)
+        delay: Optional[LayeredTotalDelay] = None   # built on the first tie
 
         def assigned_ok(j) -> bool:
             return (net.is_active(j) and
@@ -127,14 +128,17 @@ class ResourceAwareAssigner:
                 ties = [j for j in order
                         if scores[j] <= best * (1 + self.tie_tol) + 1e-12][:6]
                 if len(ties) > 1:
-                    def marginal(j):
-                        trial = place.copy()
-                        trial[i] = j
-                        filled = trial.copy()
-                        filled[filled < 0] = prev[filled < 0] if prev is not None else 0
-                        return total_delay(prev, filled, self.blocks,
-                                           self.cost, net, tau)
-                    ties.sort(key=marginal)
+                    # marginal D_T + D_mig of i on each tied device, with
+                    # unplaced blocks still on prev: only the layers block
+                    # i touches are repriced (whole-graph total_delay per
+                    # tie is quadratic in blocks and ran out t_max at 48
+                    # layers)
+                    nonlocal delay
+                    if delay is None:
+                        delay = LayeredTotalDelay(prev, self.blocks,
+                                                  self.cost, net, tau)
+                    delay.update(view)
+                    ties.sort(key=lambda j: delay.total_with(i, j))
                     rest = [j for j in order if j not in ties]
                     order = ties + rest
             return order, raw

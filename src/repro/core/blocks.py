@@ -70,6 +70,8 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Sequence
 
+import numpy as np
+
 FFN = "ffn"
 PROJ = "proj"
 HEAD = "head"
@@ -179,6 +181,9 @@ class BlockGraph:
             if (self.ffn[l] is None) == (not self.experts[l]):
                 raise ValueError(f"layer {l} needs exactly one of ffn / "
                                  f"expert blocks")
+        # per-layer head block indices, for vectorized per-device sums
+        self.head_index = [np.array([h.index for h in hs], dtype=int)
+                           for hs in self.heads]
 
     def layer_blocks(self, l: int) -> List[Block]:
         if self.ffn[l] is not None:

@@ -238,7 +238,8 @@ class IntervalController:
                     place, self.blocks, self.cost, self.net, self.tau, k=k),
                 "arrival_rate": arrival_rate,
                 "queue_depth": queue_depth,
-                "infeasible": stats.infeasible}
+                "infeasible": stats.infeasible,
+                "assign_s": stats.elapsed}
         self.place, self.perms = place, new_perms
         if new_eperms is not None:
             self.expert_perms = new_eperms

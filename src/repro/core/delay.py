@@ -66,7 +66,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from repro.core.blocks import Block, CostModel, graph_of
+from repro.core.blocks import EXPERT, FFN, Block, CostModel, graph_of
 from repro.core.network import DeviceNetwork
 
 
@@ -102,70 +102,82 @@ def _expert_stage(g, l, place, cost, tau):
     return agg
 
 
+def _layer_terms(g, l: int, place, sources, w_in: float, comp,
+                 cost: CostModel, net: DeviceNetwork, tau: int,
+                 strict_eq6: bool) -> tuple:
+    """Layer l's additive D_T terms, in the order ``inference_delay`` sums
+    them, and the (device, load fraction) sources it hands layer l+1's
+    heads.  ``sources``/``w_in`` are what layer l's heads receive;
+    ``comp`` is ``cost.compute_vector(blocks, tau)``."""
+    d_proj = int(place[g.proj[l].index])
+    hidx = g.head_index[l]
+    head_dev = place[hidx]
+    # per-device summed head compute (sequential sharing: bincount adds in
+    # head order) and per-link summed head->proj volume (serialized
+    # sharing; whole bytes, so count x volume is exact)
+    head_compute_on = np.bincount(head_dev, weights=comp[hidx],
+                                  minlength=net.n_devices)
+    n_on = np.bincount(head_dev, minlength=net.n_devices)
+    vol_to_proj = n_on * cost.head_to_proj_bytes(tau)
+
+    # every head on device j has the same chain time: take the max over
+    # the devices that host heads
+    worst = 0.0
+    for j in np.flatnonzero(n_on).tolist():
+        t_in = sum(fr * w_in / _rate(net, s, j) for s, fr in sources)
+        t_proc = _cdiv(head_compute_on[j], net.compute_avail[j])
+        t_out = vol_to_proj[j] / _rate(net, j, d_proj)
+        worst = max(worst, t_in + t_proc + t_out)
+
+    terms = [worst]
+    if not strict_eq6:
+        terms.append(_cdiv(comp[g.proj[l].index], net.compute_avail[d_proj]))
+    if g.ffn[l] is not None:
+        d_ffn = int(place[g.ffn[l].index])
+        terms.append(cost.proj_to_ffn_bytes(tau) / _rate(net, d_proj, d_ffn))
+        if not strict_eq6:
+            terms.append(_cdiv(comp[g.ffn[l].index],
+                               net.compute_avail[d_ffn]))
+        return terms, [(d_ffn, 1.0)]
+    # expert stage: router fan-out (load-fraction-scaled proj->expert
+    # transfer) + per-device expert compute, run in parallel across expert
+    # devices -> the stage is the slowest device's (transfer, compute)
+    # pair, added as two terms to keep the dense float association when
+    # collapsed
+    agg = _expert_stage(g, l, place, cost, tau)
+    w_p2f = cost.proj_to_ffn_bytes(tau)
+    stage_t = stage_c = 0.0
+    stage = -1.0
+    for d in sorted(agg):
+        fr, cp = agg[d]
+        t_x = fr * w_p2f / _rate(net, d_proj, d)
+        t_c = 0.0 if strict_eq6 else _cdiv(cp, net.compute_avail[d])
+        if t_x + t_c > stage:
+            stage, stage_t, stage_c = t_x + t_c, t_x, t_c
+    terms.append(stage_t)
+    if not strict_eq6:
+        terms.append(stage_c)
+    return terms, [(d, agg[d][0]) for d in sorted(agg)]
+
+
 def inference_delay(place: np.ndarray, blocks: Sequence[Block],
                     cost: CostModel, net: DeviceNetwork, tau: int,
                     *, strict_eq6: bool = False) -> float:
     """D_T(τ) for placement ``place``: Eq. 6 per layer, composed along the
     inter-layer edges (see module docstring)."""
     g = graph_of(blocks)
+    comp = cost.compute_vector(g.blocks, tau)
     total = 0.0
     # layer 0: token embeddings from the controller; expert layers hand a
     # (device, load fraction) SOURCE LIST to the next layer's heads — the
     # router combine — which the dense path degenerates to as [(ffn, 1.0)]
     sources = [(net.controller, 1.0)]
     w_in = cost.input_bytes(tau)
-    w_head = cost.head_to_proj_bytes(tau)
     for l in range(g.n_layers):
-        heads = g.heads[l]
-        d_proj = int(place[g.proj[l].index])
-
-        # per-device summed head compute (sequential sharing)
-        head_compute_on = np.zeros(net.n_devices)
-        for h in heads:
-            head_compute_on[place[h.index]] += cost.compute(h, tau)
-        # per-link summed head->proj volume (serialized sharing)
-        vol_to_proj = np.zeros(net.n_devices)
-        for h in heads:
-            vol_to_proj[place[h.index]] += w_head
-
-        worst = 0.0
-        for h in heads:
-            j = int(place[h.index])
-            t_in = sum(fr * w_in / _rate(net, s, j) for s, fr in sources)
-            t_proc = _cdiv(head_compute_on[j], net.compute_avail[j])
-            t_out = vol_to_proj[j] / _rate(net, j, d_proj)
-            worst = max(worst, t_in + t_proc + t_out)
-
-        total += worst
-        if not strict_eq6:
-            total += _cdiv(cost.compute(g.proj[l], tau), net.compute_avail[d_proj])
-        if g.ffn[l] is not None:
-            d_ffn = int(place[g.ffn[l].index])
-            total += cost.proj_to_ffn_bytes(tau) / _rate(net, d_proj, d_ffn)
-            if not strict_eq6:
-                total += _cdiv(cost.compute(g.ffn[l], tau),
-                               net.compute_avail[d_ffn])
-            sources = [(d_ffn, 1.0)]
-        else:
-            # expert stage: router fan-out (load-fraction-scaled
-            # proj->expert transfer) + per-device expert compute, run in
-            # parallel across expert devices -> the stage is the slowest
-            # device's (transfer, compute) pair, added as two terms to
-            # keep the dense float association when collapsed
-            agg = _expert_stage(g, l, place, cost, tau)
-            w_p2f = cost.proj_to_ffn_bytes(tau)
-            stage_t = stage_c = 0.0
-            stage = -1.0
-            for d in sorted(agg):
-                fr, cp = agg[d]
-                t_x = fr * w_p2f / _rate(net, d_proj, d)
-                t_c = 0.0 if strict_eq6 else _cdiv(cp, net.compute_avail[d])
-                if t_x + t_c > stage:
-                    stage, stage_t, stage_c = t_x + t_c, t_x, t_c
-            total += stage_t
-            if not strict_eq6:
-                total += stage_c
-            sources = [(d, agg[d][0]) for d in sorted(agg)]
+        terms, sources = _layer_terms(g, l, place, sources, w_in, comp,
+                                      cost, net, tau, strict_eq6)
+        for t in terms:
+            total += t
         w_in = cost.interlayer_bytes(tau)
     return float(total)
 
@@ -321,6 +333,96 @@ def total_delay(prev: Optional[np.ndarray], place: np.ndarray,
         migration_delay(prev, place, blocks, cost, net, tau)
 
 
+class LayeredTotalDelay:
+    """``total_delay(prev, place, ...)`` kept per layer, so that moving one
+    block reprices only the layers it touches: its own, and the next one
+    when the block feeds the next layer's heads (ffn / expert).  The terms
+    are summed in ``inference_delay``'s order, so every value equals
+    ``total_delay`` bit for bit — on a 48-layer graph one repricing costs
+    two layers instead of the whole graph."""
+
+    def __init__(self, prev: np.ndarray, blocks: Sequence[Block],
+                 cost: CostModel, net: DeviceNetwork, tau: int):
+        self.g = graph_of(blocks)
+        self.cost, self.net, self.tau = cost, net, tau
+        self.prev = np.asarray(prev, dtype=int)
+        self.comp = cost.compute_vector(self.g.blocks, tau)
+        self.mem_prev = cost.memory_vector(self.g.blocks, tau - 1)
+        self.layer = np.array([b.layer for b in self.g.blocks])
+        self.feeds_next = np.array([b.kind in (FFN, EXPERT)
+                                    for b in self.g.blocks])
+        self.place = self.prev.copy()
+        self.mig = np.zeros(len(self.g.blocks))   # prev == place: no moves
+        self.terms: list = [None] * self.g.n_layers
+        self.out: list = [None] * self.g.n_layers
+        for l in range(self.g.n_layers):
+            self.terms[l], self.out[l] = self._layer(l, self.place,
+                                                     self._sources(l))
+
+    def _sources(self, l: int):
+        return [(self.net.controller, 1.0)] if l == 0 else self.out[l - 1]
+
+    def _layer(self, l: int, place, sources):
+        w_in = self.cost.input_bytes(self.tau) if l == 0 \
+            else self.cost.interlayer_bytes(self.tau)
+        return _layer_terms(self.g, l, place, sources, w_in, self.comp,
+                            self.cost, self.net, self.tau, False)
+
+    def _mig_term(self, i: int, k: int) -> float:
+        j = int(self.prev[i])
+        return 0.0 if j == k else self.mem_prev[i] / _rate(self.net, j, k)
+
+    def _touched(self, i: int) -> list:
+        l = int(self.layer[i])
+        return [l, l + 1] if self.feeds_next[i] and l + 1 < self.g.n_layers \
+            else [l]
+
+    def update(self, place: np.ndarray):
+        """Adopt ``place`` (complete, no -1), repricing only the layers
+        whose blocks moved since the last adopted placement."""
+        moved = np.flatnonzero(place != self.place)
+        if not moved.size:
+            return
+        self.place = np.array(place, dtype=int)
+        dirty = set()
+        for i in moved:
+            self.mig[i] = self._mig_term(int(i), int(place[i]))
+            dirty.update(self._touched(int(i)))
+        for l in sorted(dirty):      # ascending: layer l reads out[l - 1]
+            self.terms[l], self.out[l] = self._layer(l, self.place,
+                                                     self._sources(l))
+
+    def total_with(self, i: int, j: int) -> float:
+        """``total_delay`` of the adopted placement with block i on j."""
+        old = self.place[i]
+        self.place[i] = j
+        try:
+            subst = {}
+            for l in self._touched(i):
+                src = subst[l - 1][1] if l - 1 in subst else self._sources(l)
+                subst[l] = self._layer(l, self.place, src)
+        finally:
+            self.place[i] = old
+        mig = self.mig.copy()
+        mig[i] = self._mig_term(i, j)
+        return self._sum([subst[l][0] if l in subst else self.terms[l]
+                          for l in range(self.g.n_layers)], mig)
+
+    def total(self) -> float:
+        """``total_delay`` of the adopted placement."""
+        return self._sum(self.terms, self.mig)
+
+    @staticmethod
+    def _sum(layer_terms, mig) -> float:
+        total = 0.0
+        for terms in layer_terms:
+            for t in terms:
+                total += t
+        # add.accumulate is a sequential left-to-right sum: the same
+        # association as migration_delay's loop (unmoved blocks add 0.0)
+        return float(total) + float(np.add.accumulate(mig)[-1])
+
+
 def pipelined_total_delay(prev: Optional[np.ndarray], place: np.ndarray,
                           blocks: Sequence[Block], cost: CostModel,
                           net: DeviceNetwork, tau: int, *, k: int = 1,
@@ -348,19 +450,45 @@ def revert_unpaying_migrations(prev: Optional[np.ndarray],
     if prev is None:
         return place
     current = place.copy()
-    cur_val = pipelined_total_delay(prev, current, blocks, cost, net, tau,
-                                    k=k)
+    # memory is integral bytes, so the running per-device sums stay exact
+    mem = cost.memory_vector(blocks, tau)
+    use = memory_usage(current, blocks, cost, net, tau)
+    usable = net.mem_usable() + 1e-9
+    if k == 1:
+        # D_T + D_mig repriced per layer (bit-identical to total_delay):
+        # a whole-graph evaluation per migrated block is quadratic in
+        # blocks at full model depth
+        delay = LayeredTotalDelay(prev, blocks, cost, net, tau)
+        delay.update(current)
+        cur_val = delay.total()
+    else:
+        cur_val = pipelined_total_delay(prev, current, blocks, cost, net,
+                                        tau, k=k)
     for i in np.flatnonzero(current != prev):
-        if not net.is_active(int(prev[i])):
+        src, dst = int(current[i]), int(prev[i])
+        if not net.is_active(dst):
             continue  # forced evacuation: reverting would re-kill the block
-        trial = current.copy()
-        trial[i] = prev[i]
-        if not memory_feasible(trial, blocks, cost, net, tau):
+        use[src] -= mem[i]
+        use[dst] += mem[i]
+        if not np.all(use <= usable):
+            use[src] += mem[i]
+            use[dst] -= mem[i]
             continue
-        val = pipelined_total_delay(prev, trial, blocks, cost, net, tau,
-                                    k=k)
+        if k == 1:
+            val = delay.total_with(int(i), dst)
+        else:
+            trial = current.copy()
+            trial[i] = dst
+            val = pipelined_total_delay(prev, trial, blocks, cost, net, tau,
+                                        k=k)
         if val <= cur_val - min_gain:
-            current, cur_val = trial, val
+            current[i] = dst
+            cur_val = val
+            if k == 1:
+                delay.update(current)
+        else:
+            use[src] += mem[i]
+            use[dst] -= mem[i]
     return current
 
 

@@ -3,6 +3,9 @@ paper's interval controller (Algorithm 1 + migrations) in the loop.
 
   PYTHONPATH=src python -m repro.launch.serve --arch musicgen-large \
       --reduced --requests 8 --tokens 24 [--straggler 0]
+
+``main`` returns the engine it served with, so a caller (``chip_smoke.py``)
+can inspect what the run did.
 """
 from __future__ import annotations
 
@@ -12,6 +15,7 @@ import time
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import reduced_for_cpu
 from repro.serving.engine import make_engine
 
@@ -22,12 +26,19 @@ def main(argv=None):
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
+    ap.add_argument("--min-prompt-len", type=int, default=None,
+                    help="shortest prompt under --mixed-lengths "
+                         "(default: half of --prompt-len)")
+    ap.add_argument("--max-seq", type=int, default=None,
+                    help="per-slot token capacity (default: prompt + "
+                         "tokens + 8, rounded up to a page)")
     ap.add_argument("--tokens", type=int, default=24)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--lam", type=int, default=8,
                     help="controller interval (decode steps)")
     ap.add_argument("--straggler", type=int, default=-1,
-                    help="inject a 20x slowdown on this mesh slot")
+                    help="slow this device 20x after the first "
+                         "controller interval")
     ap.add_argument("--engine", default="auto",
                     choices=("auto", "continuous", "wave"),
                     help="continuous batching (default for linear-cache "
@@ -59,6 +70,7 @@ def main(argv=None):
                     help="tokens per KV page (--paged)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_for_cpu(cfg)
@@ -70,7 +82,7 @@ def main(argv=None):
         # pages divide max_seq; the paged path is continuous-engine only
         kw.update(paged=True, page_size=args.page_size)
         mode = "continuous"
-    max_seq = args.prompt_len + args.tokens + 8
+    max_seq = args.max_seq or args.prompt_len + args.tokens + 8
     if args.paged and max_seq % args.page_size:
         max_seq += args.page_size - max_seq % args.page_size
     eng = make_engine(cfg, mode=mode, n_slots=args.slots,
@@ -79,14 +91,23 @@ def main(argv=None):
                       pipeline_k=args.pipeline_k, search=args.search, **kw)
     print(f"[serve] engine: {type(eng).__name__}")
     if args.straggler >= 0:
-        eng.net.inject_straggler(args.straggler, slowdown=20.0)
-        print(f"[serve] injected straggler on slot {args.straggler}")
+        # the device slows down mid-decode, once the first controller
+        # interval has placed the model: later intervals see it and move
+        # heads away (slowed before the first plan, the device is simply
+        # left out of that plan and nothing ever migrates)
+        def slow_after_first_interval(req, tok, done):
+            if eng.migration_log:
+                eng.token_sink = None
+                eng.net.inject_straggler(args.straggler, slowdown=20.0)
+                print(f"[serve] injected straggler on slot {args.straggler}"
+                      f" at decode step {eng.decode_steps}")
+        eng.token_sink = slow_after_first_interval
     rng = np.random.default_rng(0)
     t0 = time.time()
     for i in range(args.requests):
         if args.mixed_lengths:
-            plen = int(rng.integers(max(2, args.prompt_len // 2),
-                                    args.prompt_len + 1))
+            lo = args.min_prompt_len or args.prompt_len // 2
+            plen = int(rng.integers(max(2, lo), args.prompt_len + 1))
         else:
             plen = args.prompt_len
         eng.submit(rng.integers(0, cfg.vocab_size, size=plen),
@@ -107,7 +128,7 @@ def main(argv=None):
         print(f"  req {r.rid}: ttft={r.t_first - r.t_submit:.2f}s "
               f"total={r.t_done - r.t_submit:.2f}s "
               f"tokens={r.out_tokens[:8]}...")
-    return done
+    return eng
 
 
 if __name__ == "__main__":

@@ -319,7 +319,10 @@ class _EngineBase:
         this conversion, only the *cadence* at which intervals fire).
         The observed arrival rate and queue depth ride along into the
         interval record, so the controller sees LOAD, not just occupancy
-        (the honest signal traffic-adaptive search will consume)."""
+        (the honest signal traffic-adaptive search will consume).
+        ``plan["plan_s"]`` is the host time this took: the decode stall
+        the controller costs per interval."""
+        t0 = time.monotonic()
         self.net.step_background_load()
         # close the fault-tolerance loop: C_j(τ) comes from the heartbeat
         # monitor's step-time EWMAs scaling the background-load estimate.
@@ -329,9 +332,11 @@ class _EngineBase:
         self.controller.observe_monitor(self.monitor,
                                         peak_flops=self.net.compute_avail)
         rate, depth = self._load_signal()
-        return self.controller.step_interval(tau=self._tau_of(tau_tokens),
+        plan = self.controller.step_interval(tau=self._tau_of(tau_tokens),
                                              arrival_rate=rate,
                                              queue_depth=depth)
+        plan["plan_s"] = time.monotonic() - t0
+        return plan
 
     def _tau_of(self, tau_tokens: Optional[int]) -> Optional[int]:
         """Occupancy (tokens) -> interval index τ of the cost model."""
@@ -528,6 +533,8 @@ class _EngineBase:
             "expert_mig_bytes": self._expert_migration_bytes(epairs),
             "d_mig_est": plan["d_mig_est"],
             "d_pipe_est": plan.get("d_pipe_est"),
+            "infeasible": plan.get("infeasible"),
+            "plan_s": plan.get("plan_s"),
             "applied": applied, "reason": reason,
             "expert_applied": expert_applied,
             "expert_reason": expert_reason})
