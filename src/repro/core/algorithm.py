@@ -41,7 +41,6 @@ class AlgoStats:
     backtracks: int = 0
     elapsed: float = 0.0
     infeasible: bool = False
-    score_evals: int = 0
 
 
 class ResourceAwareAssigner:
@@ -118,7 +117,6 @@ class ResourceAwareAssigner:
                 score(bl, j, self.blocks, view, self.cost, net, tau,
                       deadline=self.deadline, mem_used=mem_used,
                       compute_used=comp_used) for j in range(V)])
-            stats.score_evals += V
             scores = raw.copy()
             if prev is not None:
                 scores[prev[i]] *= self.hysteresis  # anti-thrash stickiness

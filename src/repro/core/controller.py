@@ -32,6 +32,7 @@ from repro.core.placement_bridge import (apply_head_perm,
                                          migration_pairs_layers,
                                          placement_to_expert_perms,
                                          placement_to_perms, relative_perms)
+from repro.runtime.spans import SpanRecorder
 
 
 @dataclasses.dataclass
@@ -66,7 +67,8 @@ class IntervalController:
     """Runs Algorithm 1 every λ generated tokens and emits migration plans."""
 
     def __init__(self, n_heads: int, cost: CostModel, net: DeviceNetwork,
-                 cfg: ControllerConfig = ControllerConfig()):
+                 cfg: ControllerConfig = ControllerConfig(),
+                 spans: Optional[SpanRecorder] = None):
         self.n_layers = cost.n_layers if cost.layer_mode == "graph" else 1
         self.blocks: List[Block] = make_blocks(n_heads, self.n_layers,
                                                cost.n_experts,
@@ -106,7 +108,9 @@ class IntervalController:
         # (n_layers, slots·eps) physical expert-row layout (MoE archs)
         self.expert_perms: Optional[np.ndarray] = None
         self.tau = 0
-        self.history: List[dict] = []
+        # ctl.assign / payback / perms / estimate spans (runtime.spans);
+        # the serving engine passes its own recorder
+        self.spans = spans if spans is not None else SpanRecorder()
 
     @property
     def perm(self) -> Optional[np.ndarray]:
@@ -178,53 +182,57 @@ class IntervalController:
 
         ``arrival_rate`` (requests per scheduler step since the last
         interval) and ``queue_depth`` (backlog at the interval boundary)
-        are the engine's observed LOAD — recorded into the plan and
-        history so the controller's view covers the arrival process, not
-        just resident occupancy.  Today they are telemetry; they are the
+        are the engine's observed LOAD — recorded into the plan so the
+        controller's view covers the arrival process, not just resident
+        occupancy.  Today they are telemetry; they are the
         input the traffic-adaptive search (ROADMAP) will act on."""
         self.tau = max(1, int(tau)) if tau is not None else self.tau + 1
         prev = self.place
         k = self.cfg.pipeline_k
-        if self._policy is not None:
-            # bottleneck mode: the policy already refines, filters (with
-            # min_gain) and runs the bottleneck-targeted search
-            place = self._policy.place(self.net, self.tau, prev)
-            stats = self._policy.last_stats
+        span = self.spans.span
+        with span("ctl.assign"):
+            if self._policy is not None:
+                # bottleneck mode: the policy already refines, filters
+                # (with min_gain) and runs the bottleneck-targeted search
+                place = self._policy.place(self.net, self.tau, prev)
+                stats = self._policy.last_stats
+            else:
+                place, stats = self.assigner.assign(self.net, self.tau, prev)
             if place is None:
                 place = prev if prev is not None else \
                     np.zeros(len(self.blocks), dtype=int)
-        else:
-            place, stats = self.assigner.assign(self.net, self.tau, prev)
-            if place is None:
-                place = prev if prev is not None else \
-                    np.zeros(len(self.blocks), dtype=int)
+        if self._policy is None:
             # objective filter: keep migrations only if they pay (§III.G).
             # With pipeline_k > 1 the objective is D_pipe(K) + D_mig — a
             # move that lengthens the critical path but relieves the
             # bottleneck resource can now win (k=1 is total_delay
             # bit-for-bit).
-            place = revert_unpaying_migrations(prev, place, self.blocks,
-                                               self.cost, self.net, self.tau,
-                                               k=k,
-                                               min_gain=self.cfg.min_gain)
+            with span("ctl.payback"):
+                place = revert_unpaying_migrations(
+                    prev, place, self.blocks, self.cost, self.net, self.tau,
+                    k=k, min_gain=self.cfg.min_gain)
         n_slots = self.net.n_devices
-        new_perms = placement_to_perms(place, self.blocks, n_slots,
-                                       self.cfg.heads_per_slot,
-                                       self.cfg.group_size)
-        pairs = [] if self.perms is None else \
-            migration_pairs_layers(self.perms, new_perms,
-                                   self.cfg.heads_per_slot)
-        new_eperms = None
-        epairs: List[tuple] = []
-        if self.has_experts:
-            new_eperms = placement_to_expert_perms(
-                place, self.blocks, n_slots, self.experts_per_slot,
-                self.cost.expert_replicas)
-            if self.expert_perms is not None:
-                epairs = migration_pairs_layers(self.expert_perms, new_eperms,
-                                                self.experts_per_slot)
-        d_mig = migration_delay(prev, place, self.blocks, self.cost,
-                                self.net, self.tau)
+        with span("ctl.perms"):
+            new_perms = placement_to_perms(place, self.blocks, n_slots,
+                                           self.cfg.heads_per_slot,
+                                           self.cfg.group_size)
+            pairs = [] if self.perms is None else \
+                migration_pairs_layers(self.perms, new_perms,
+                                       self.cfg.heads_per_slot)
+            new_eperms = None
+            epairs: List[tuple] = []
+            if self.has_experts:
+                new_eperms = placement_to_expert_perms(
+                    place, self.blocks, n_slots, self.experts_per_slot,
+                    self.cost.expert_replicas)
+                if self.expert_perms is not None:
+                    epairs = migration_pairs_layers(
+                        self.expert_perms, new_eperms, self.experts_per_slot)
+        with span("ctl.estimate"):
+            d_mig = migration_delay(prev, place, self.blocks, self.cost,
+                                    self.net, self.tau)
+            d_pipe = pipelined_inference_delay(place, self.blocks, self.cost,
+                                               self.net, self.tau, k=k)
         plan = {"tau": self.tau, "place": place,
                 "perms": new_perms, "prev_perms": self.perms,
                 "perm": new_perms[0],
@@ -234,8 +242,7 @@ class IntervalController:
                 "prev_expert_perms": self.expert_perms,
                 "expert_migrations": epairs,
                 "d_mig_est": d_mig,
-                "d_pipe_est": pipelined_inference_delay(
-                    place, self.blocks, self.cost, self.net, self.tau, k=k),
+                "d_pipe_est": d_pipe,
                 "arrival_rate": arrival_rate,
                 "queue_depth": queue_depth,
                 "infeasible": stats.infeasible,
@@ -243,12 +250,6 @@ class IntervalController:
         self.place, self.perms = place, new_perms
         if new_eperms is not None:
             self.expert_perms = new_eperms
-        self.history.append({"tau": self.tau, "n_migrations": len(pairs),
-                             "n_expert_migrations": len(epairs),
-                             "d_mig_est": d_mig,
-                             "arrival_rate": arrival_rate,
-                             "queue_depth": queue_depth,
-                             "infeasible": stats.infeasible})
         return plan
 
     # ------------------------------------------------------------- churn
@@ -272,8 +273,6 @@ class IntervalController:
                 f"device {device}'s blocks (n_active={self.net.n_active})")
         plan["evacuation"] = True
         plan["failed_device"] = int(device)
-        self.history[-1]["evacuation"] = True
-        self.history[-1]["failed_device"] = int(device)
         return plan
 
     def handle_rejoin(self, device: int,
@@ -286,8 +285,6 @@ class IntervalController:
         plan = self.step_interval(tau=tau)
         plan["expansion"] = True
         plan["rejoined_device"] = int(device)
-        self.history[-1]["expansion"] = True
-        self.history[-1]["rejoined_device"] = int(device)
         return plan
 
     # ---------------------------------------------------------------- act

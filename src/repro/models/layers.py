@@ -431,10 +431,11 @@ def self_attention_block(cfg: ModelConfig, p: dict, hd: HeadDims, x,
             jnp.arange(Tmax, dtype=jnp.int32)[None, :], (B, Tmax))
 
         def scatter(buf, new):
-            flat = buf.reshape((n_pages * P,) + buf.shape[2:])
-            flat = flat.at[w_flat].set(
-                new.reshape((B * S,) + new.shape[2:]), mode="drop")
-            return flat.reshape(buf.shape)
+            with jax.named_scope("kv_write"):
+                flat = buf.reshape((n_pages * P,) + buf.shape[2:])
+                flat = flat.at[w_flat].set(
+                    new.reshape((B * S,) + new.shape[2:]), mode="drop")
+                return flat.reshape(buf.shape)
 
         def gather(buf):
             flat = buf.reshape((n_pages * P,) + buf.shape[2:])

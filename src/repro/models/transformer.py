@@ -126,32 +126,40 @@ class TransformerLM:
     def _layer(self, p: dict, x, positions, cache, cache_pos,
                head_rows=None, head_inv=None, page_map=None,
                write_valid=None):
+        # named scopes ("norm", "attention" with its "kv_write", "mlp",
+        # "lm_head") reach the device trace as the ops' op_name prefixes,
+        # so device time can be split by part of the layer
         cfg, part = self.cfg, self.part
-        h = L.apply_norm(cfg, p, "ln1", x)
+        with jax.named_scope("norm"):
+            h = L.apply_norm(cfg, p, "ln1", x)
         # explicit SP->TP boundary ON THE BF16 TENSOR: norms run in the
         # sequence-sharded region (pointwise over D), the all-gather happens
         # here rather than on an f32 intermediate chosen by GSPMD
         # (EXPERIMENTS.md §Perf H2-1: halves boundary collective bytes and
         # avoids SPMD "involuntary full rematerialization" reshards).
         h = part.constrain(h, ("batch", "seq", "d_model"))
-        attn_out, new_cache = L.self_attention_block(
-            cfg, p["attn"], self.hd, h, positions, part,
-            cache=cache, cache_pos=cache_pos, window=self.window,
-            use_kernel=self.use_kernel, head_rows=head_rows,
-            head_inv=head_inv, page_map=page_map, write_valid=write_valid)
+        with jax.named_scope("attention"):
+            attn_out, new_cache = L.self_attention_block(
+                cfg, p["attn"], self.hd, h, positions, part,
+                cache=cache, cache_pos=cache_pos, window=self.window,
+                use_kernel=self.use_kernel, head_rows=head_rows,
+                head_inv=head_inv, page_map=page_map,
+                write_valid=write_valid)
         x = x + attn_out
-        h = L.apply_norm(cfg, p, "ln2", x)
+        with jax.named_scope("norm"):
+            h = L.apply_norm(cfg, p, "ln2", x)
         h = part.constrain(h, ("batch", "seq", "d_model"))
         aux = jnp.zeros((), jnp.float32)
         freq = None
-        if cfg.is_moe:
-            if self.capacity_moe:
-                mlp_out, aux, freq = moe_block_capacity(
-                    cfg, p["moe"], h, part, self.capacity_factor)
+        with jax.named_scope("mlp"):
+            if cfg.is_moe:
+                if self.capacity_moe:
+                    mlp_out, aux, freq = moe_block_capacity(
+                        cfg, p["moe"], h, part, self.capacity_factor)
+                else:
+                    mlp_out, aux, freq = moe_block(cfg, p["moe"], h, part)
             else:
-                mlp_out, aux, freq = moe_block(cfg, p["moe"], h, part)
-        else:
-            mlp_out = L.mlp_block(cfg, p["mlp"], h, part)
+                mlp_out = L.mlp_block(cfg, p["mlp"], h, part)
         return x + mlp_out, new_cache, aux, freq
 
     def _cross_layer(self, p: dict, x, img_kv, img_mask):
@@ -375,8 +383,10 @@ class TransformerLM:
             img_kv=state.get("img_kv"), img_mask=state.get("img_mask"),
             head_rows=state.get("head_rows"), head_inv=state.get("head_inv"),
             page_map=page_map)
-        x = L.apply_norm(cfg, params, "ln_f", x)
-        logits = L.unembed(cfg, params, x, part)
+        with jax.named_scope("norm"):
+            x = L.apply_norm(cfg, params, "ln_f", x)
+        with jax.named_scope("lm_head"):
+            logits = L.unembed(cfg, params, x, part)
         if per_slot:
             # clamp retired slots at the cache edge (their writes drop);
             # the paged extent is the page table's logical span, not a
@@ -530,10 +540,12 @@ class TransformerLM:
         x, new_cache, _, _ = self._run_layers(
             params, x, positions, state["cache"], None,
             page_map=page_row, write_valid=valid)
-        x = L.apply_norm(cfg, params, "ln_f", x)
+        with jax.named_scope("norm"):
+            x = L.apply_norm(cfg, params, "ln_f", x)
         last = jnp.take_along_axis(
             x, jnp.maximum(length - 1, 0)[None, None, None], axis=1)
-        logits = L.unembed(cfg, params, last, part)
+        with jax.named_scope("lm_head"):
+            logits = L.unembed(cfg, params, last, part)
         pos = jax.lax.dynamic_update_slice(
             state["pos"], (start + length)[None], (row,))
         return logits[:, 0], dict(state, cache=new_cache, pos=pos)

@@ -58,6 +58,7 @@ from repro.core.controller import ControllerConfig, IntervalController
 from repro.core.network import DeviceNetwork
 from repro.models.api import build_model
 from repro.runtime.fault_tolerance import HeartbeatMonitor
+from repro.runtime.spans import SpanRecorder
 
 
 class UnsupportedArchError(NotImplementedError):
@@ -75,6 +76,7 @@ class Request:
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     t_submit: float = 0.0
+    t_admit: float = 0.0          # popped from the queue into a slot
     t_first: float = 0.0
     t_done: float = 0.0
     img: Optional[np.ndarray] = None       # (I, D) VLM patch embeddings
@@ -222,12 +224,15 @@ class _EngineBase:
                 f"{self.net.n_devices}x{heads_per_slot} head-slot geometry "
                 f"— pick a device count whose head positions are a "
                 f"multiple of the group size")
+        # spans of the served path (runtime.spans): off unless enabled or
+        # a profiler trace runs; the controller records into the same one
+        self.spans = SpanRecorder()
         self.controller = IntervalController(
             max(cfg.n_heads, 1), self.cost, self.net,
             ControllerConfig(lam=lam, heads_per_slot=heads_per_slot,
                              group_size=group,
                              pipeline_k=self.pipeline_k,
-                             search=self.search))
+                             search=self.search), spans=self.spans)
         self.monitor = HeartbeatMonitor(self.net.n_devices)
         self.lam = lam
         self.decode_steps = 0
@@ -321,21 +326,26 @@ class _EngineBase:
         interval record, so the controller sees LOAD, not just occupancy
         (the honest signal traffic-adaptive search will consume).
         ``plan["plan_s"]`` is the host time this took: the decode stall
-        the controller costs per interval."""
-        t0 = time.monotonic()
-        self.net.step_background_load()
-        # close the fault-tolerance loop: C_j(τ) comes from the heartbeat
-        # monitor's step-time EWMAs scaling the background-load estimate.
-        # Uniform step times leave the estimate untouched (ratio 1), so a
-        # churn-free run observes exactly what direct observation would;
-        # hung/failed slots estimate to zero.
-        self.controller.observe_monitor(self.monitor,
-                                        peak_flops=self.net.compute_avail)
-        rate, depth = self._load_signal()
-        plan = self.controller.step_interval(tau=self._tau_of(tau_tokens),
-                                             arrival_rate=rate,
-                                             queue_depth=depth)
-        plan["plan_s"] = time.monotonic() - t0
+        the controller costs per interval (the ``ctl.interval`` span's
+        boundaries when spans are on)."""
+        span = self.spans.span
+        with span("ctl.interval") as iv:
+            t0 = iv.t0 if iv else time.monotonic()
+            with span("ctl.observe"):
+                self.net.step_background_load()
+                # close the fault-tolerance loop: C_j(τ) comes from the
+                # heartbeat monitor's step-time EWMAs scaling the
+                # background-load estimate.  Uniform step times leave the
+                # estimate untouched (ratio 1), so a churn-free run
+                # observes exactly what direct observation would;
+                # hung/failed slots estimate to zero.
+                self.controller.observe_monitor(
+                    self.monitor, peak_flops=self.net.compute_avail)
+                rate, depth = self._load_signal()
+            plan = self.controller.step_interval(
+                tau=self._tau_of(tau_tokens), arrival_rate=rate,
+                queue_depth=depth)
+        plan["plan_s"] = (iv.t1 if iv else time.monotonic()) - t0
         return plan
 
     def _tau_of(self, tau_tokens: Optional[int]) -> Optional[int]:
@@ -666,6 +676,13 @@ class ServingEngine(_EngineBase):
             collections.deque(maxlen=4096)    # {step, slot, rid, bucket}
         self.prefill_buckets_used: set = set()
         self.slot_busy_steps = 0              # sum of active slots per step
+        # paged counters: chunk dispatches, page-table mounts, and Σ live /
+        # Σ pooled pages over the allocators at each decode dispatch (their
+        # ratio is the mean share of the pool that holds tokens)
+        self.prefill_chunks = 0
+        self.page_mounts = 0
+        self.live_page_steps = 0
+        self.pool_page_steps = 0
         # elastic churn: recovery events (fail/rejoin) with their replay
         # accounting, plus the client-visible tokens dropped by recovery
         # (teacher-forced replay re-derives every stream, so this stays 0
@@ -784,11 +801,18 @@ class ServingEngine(_EngineBase):
             # recycled pages cannot be corrupted by a retired slot
             g, row = self._group_of(slot)
             self.allocators[g].release(row)
+            self._mount(g, row, 0)
+        self._emit_done(r)
+
+    def _mount(self, g: int, row: int, pos: int):
+        """Write slot ``row``'s page-table row from group ``g``'s
+        allocator (and its position) into the group's decode state."""
+        self.page_mounts += 1
+        with self.spans.span("kv.mount", arg=row):
             self.states[g] = self._mount_jit(
                 self.states[g], jnp.int32(row),
                 jnp.asarray(self.allocators[g].page_map_row(row)),
-                jnp.int32(0))
-        self._emit_done(r)
+                jnp.int32(pos))
 
     def _finish_check(self, slot: int):
         r = self.slots[slot]
@@ -810,6 +834,7 @@ class ServingEngine(_EngineBase):
                     return      # head-of-line: wait for pages to free
                 continue
             r = self.queue.pop(0)
+            r.t_admit = time.monotonic()
             L0 = len(r.prompt)
             Lb = self._bucket(L0)
             toks = np.zeros((1, Lb), np.int32)
@@ -852,32 +877,38 @@ class ServingEngine(_EngineBase):
         if not alloc.can_admit(L0, horizon):
             return False
         self.queue.pop(0)
-        pages = alloc.admit(row, n_tokens=L0, horizon=horizon)
-        self.states[g] = self._mount_jit(
-            self.states[g], jnp.int32(row),
-            jnp.asarray(alloc.page_map_row(row)), jnp.int32(0))
-        C = self.prefill_chunk
-        logits = None
-        for c0 in range(0, max(L0, 1), C):
-            n = min(C, L0 - c0)
-            toks = np.zeros((1, C), np.int32)
-            toks[0, :n] = r.prompt[c0:c0 + n]
-            logits, self.states[g] = self._paged_prefill_jit(
-                self.params, self.states[g], jnp.asarray(toks),
-                jnp.int32(row), jnp.int32(c0), jnp.int32(n))
-        self.prefill_buckets_used.add(C)
-        r.t_first = time.monotonic()
-        self.slots[s] = r
-        # rpr: ignore[RPR004] -- the admission-time sample IS the
-        # scheduler's sync point: the first token must reach the host
-        # to seed _next before the slot can decode
-        tok = int(self._sample(logits)[0])
-        self._next[s] = tok
-        self._emit_token(r, tok)
-        self.admission_log.append({"step": self.decode_steps, "slot": s,
-                                   "rid": r.rid, "bucket": C,
-                                   "pages": len(pages)})
-        self._finish_check(s)
+        r.t_admit = time.monotonic()
+        span = self.spans.span
+        with span("sched.admit", rid=r.rid, arg=r.t_admit - r.t_submit,
+                  t0=r.t_admit):
+            pages = alloc.admit(row, n_tokens=L0, horizon=horizon)
+            self._mount(g, row, 0)
+            C = self.prefill_chunk
+            logits = None
+            for c0 in range(0, max(L0, 1), C):
+                n = min(C, L0 - c0)
+                toks = np.zeros((1, C), np.int32)
+                toks[0, :n] = r.prompt[c0:c0 + n]
+                with span("model.prefill_chunk", rid=r.rid, arg=c0):
+                    logits, self.states[g] = self._paged_prefill_jit(
+                        self.params, self.states[g], jnp.asarray(toks),
+                        jnp.int32(row), jnp.int32(c0), jnp.int32(n))
+                self.prefill_chunks += 1
+            self.prefill_buckets_used.add(C)
+            # the host waits here for the prompt's last chunk
+            with span("sched.first_token", rid=r.rid) as ft:
+                r.t_first = ft.t0 if ft else time.monotonic()
+                self.slots[s] = r
+                # rpr: ignore[RPR004] -- the admission-time sample IS the
+                # scheduler's sync point: the first token must reach the
+                # host to seed _next before the slot can decode
+                tok = int(self._sample(logits)[0])
+            self._next[s] = tok
+            self._emit_token(r, tok)
+            self.admission_log.append({"step": self.decode_steps, "slot": s,
+                                       "rid": r.rid, "bucket": C,
+                                       "pages": len(pages)})
+            self._finish_check(s)
         return True
 
     def _ensure_pages(self, g: int, active: List[int], lo: int):
@@ -892,10 +923,7 @@ class ServingEngine(_EngineBase):
             write_pos = len(r.prompt) + len(r.out_tokens) - 1
             if write_pos >= alloc.pages_for(row) * self.page_size:
                 alloc.extend(row, write_pos + 1)
-                self.states[g] = self._mount_jit(
-                    self.states[g], jnp.int32(row),
-                    jnp.asarray(alloc.page_map_row(row)),
-                    jnp.int32(write_pos))
+                self._mount(g, row, write_pos)
 
     def _live_cache_tokens(self) -> int:
         """Paged engines move only allocated pages (page-rounded live
@@ -934,7 +962,15 @@ class ServingEngine(_EngineBase):
 
         An empty due group is a pipeline bubble: the step still advances
         the phase clock (in-flight depth is bounded by slot occupancy) but
-        produces no tokens."""
+        produces no tokens.
+
+        The step is the root span (``sched.step``): the admission, decode,
+        sampling, emit, controller and migration spans nest inside it."""
+        with self.spans.root("sched.step", arg=self.decode_steps):
+            return self._step()
+
+    def _step(self) -> bool:
+        span = self.spans.span
         self._admit()
         if not self._active():
             return False
@@ -944,23 +980,32 @@ class ServingEngine(_EngineBase):
         if active:
             if self.paged:
                 self._ensure_pages(g, active, lo)
-            t0 = time.monotonic()
-            nxt = self._next[lo:lo + self.rows_per_group]
-            logits, self.states[g] = self._decode_jit(
-                self.params, self.states[g], jnp.asarray(nxt))
-            jax.block_until_ready(logits)
-            dt = time.monotonic() - t0
-            toks = self._sample(logits)
+                self.live_page_steps += sum(a.live_pages
+                                            for a in self.allocators)
+                self.pool_page_steps += sum(a.n_pages
+                                            for a in self.allocators)
+            with span("model.decode_dispatch") as sp:
+                t0 = sp.t0 if sp else time.monotonic()
+                nxt = self._next[lo:lo + self.rows_per_group]
+                logits, self.states[g] = self._decode_jit(
+                    self.params, self.states[g], jnp.asarray(nxt))
+            with span("model.decode_wait") as sp:
+                jax.block_until_ready(logits)
+            dt = (sp.t1 if sp else time.monotonic()) - t0
+            with span("sched.sample"):
+                toks = self._sample(logits)
         self.decode_steps += 1
         if active:
             self.slot_busy_steps += len(active)
-            for s in active:
-                # rpr: ignore[RPR004] -- post-block_until_ready host read:
-                # the scheduler needs concrete tokens for retire/admit
-                tok = int(toks[s - lo])
-                self._emit_token(self.slots[s], tok)
-                self._next[s] = tok
-                self._finish_check(s)
+            with span("sched.emit"):
+                for s in active:
+                    # rpr: ignore[RPR004] -- post-block_until_ready host
+                    # read: the scheduler needs concrete tokens for
+                    # retire/admit
+                    tok = int(toks[s - lo])
+                    self._emit_token(self.slots[s], tok)
+                    self._next[s] = tok
+                    self._finish_check(s)
             self._record_step(dt)
         # migration cadence scales with the in-flight depth: a slot emits
         # one token every pipeline_k steps, so λ tokens per slot = λ·K
@@ -981,20 +1026,31 @@ class ServingEngine(_EngineBase):
         """Execute a controller plan physically on every in-flight group:
         cache/weight permutations (weights once), expert weight rows once,
         kernel gather maps, interval log.  Shared by the periodic interval
-        and the churn paths (failure evacuation / rejoin expansion)."""
-        applied, reason = False, None
-        if plan["migrations"]:
-            for i in range(self.pipeline_k):
-                self.states[i], applied, reason = self._migrate_state(
-                    self.states[i], plan, permute_params=(i == 0))
-        if applied:
-            # weights/caches now sit in the plan's layout; the kernel
-            # gather maps must follow the same source of truth
-            self._phys_perms = plan["perms"]
-        # expert rows are weight-only state shared by all groups:
-        # permute them exactly once per plan
-        e_applied, e_reason = self._migrate_experts(plan)
-        self._refresh_head_rows(plan)
+        and the churn paths (failure evacuation / rejoin expansion).
+
+        With spans on, ``mig.apply`` closes only once the permuted states
+        and weights are ready, so it times the stall the permute causes
+        (the next decode waits for them anyway)."""
+        span = self.spans.span
+        with span("mig.apply", arg=len(plan["migrations"])) as sp:
+            applied, reason = False, None
+            if plan["migrations"]:
+                for i in range(self.pipeline_k):
+                    with span("mig.permute", arg=i):
+                        self.states[i], applied, reason = \
+                            self._migrate_state(self.states[i], plan,
+                                                permute_params=(i == 0))
+            if applied:
+                # weights/caches now sit in the plan's layout; the kernel
+                # gather maps must follow the same source of truth
+                self._phys_perms = plan["perms"]
+            # expert rows are weight-only state shared by all groups:
+            # permute them exactly once per plan
+            e_applied, e_reason = self._migrate_experts(plan)
+            with span("mig.head_rows"):
+                self._refresh_head_rows(plan)
+            if sp is not None:
+                jax.block_until_ready((self.states, self.params))
         self._log_interval(plan, applied, reason, e_applied, e_reason)
 
     def run(self, max_steps: int = 10_000):
@@ -1134,9 +1190,7 @@ class ServingEngine(_EngineBase):
             alloc = self.allocators[g]
             horizon = min(L0 + r.max_new_tokens + 1, self.max_seq)
             alloc.admit(row, n_tokens=L0, horizon=horizon)
-            self.states[g] = self._mount_jit(
-                self.states[g], jnp.int32(row),
-                jnp.asarray(alloc.page_map_row(row)), jnp.int32(0))
+            self._mount(g, row, 0)
             C = self.prefill_chunk
             for c0 in range(0, max(L0, 1), C):
                 n = min(C, L0 - c0)
@@ -1162,9 +1216,7 @@ class ServingEngine(_EngineBase):
         alloc = self.allocators[g]
         if write_pos >= alloc.pages_for(row) * self.page_size:
             alloc.extend(row, write_pos + 1)
-            self.states[g] = self._mount_jit(
-                self.states[g], jnp.int32(row),
-                jnp.asarray(alloc.page_map_row(row)), jnp.int32(write_pos))
+            self._mount(g, row, write_pos)
 
 
 class WaveServingEngine(_EngineBase):
