@@ -30,7 +30,7 @@ import numpy as np
 from repro.core.blocks import Block, CostModel, graph_of
 from repro.core.delay import LayeredTotalDelay
 from repro.core.network import DeviceNetwork
-from repro.core.scoring import score
+from repro.core.scoring import block_scores
 
 INFEASIBLE = None
 
@@ -41,6 +41,61 @@ class AlgoStats:
     backtracks: int = 0
     elapsed: float = 0.0
     infeasible: bool = False
+
+
+class _Tentative:
+    """Algorithm 1's in-round state: the tentative placement (-1 = not yet
+    placed), the load it puts on each device, and the view the scores and
+    the tie-break read — the placement overlaid on ``prev`` — kept where
+    the placement is written, with the blocks whose view changed since the
+    delay model last adopted it."""
+
+    def __init__(self, prev: Optional[np.ndarray], mem: np.ndarray,
+                 comp: np.ndarray, n_devices: int):
+        B = len(mem)
+        self.base = np.full(B, -1, dtype=int) if prev is None \
+            else np.asarray(prev, dtype=int)
+        self.place = np.full(B, -1, dtype=int)
+        self.view = self.base.copy()
+        self.changed: List[int] = []
+        self.mem, self.comp = mem, comp
+        self.mem_used = np.zeros(n_devices)
+        self.comp_used = np.zeros(n_devices)
+
+    def _show(self, i: int, j: int):
+        if self.view[i] != j:
+            self.view[i] = j
+            self.changed.append(i)
+
+    def put(self, i: int, j: int):
+        """Place the unplaced block i on j."""
+        self.place[i] = j
+        self.mem_used[j] += self.mem[i]
+        self.comp_used[j] += self.comp[i]
+        self._show(i, j)
+
+    def take(self, i: int):
+        """Unplace block i (no-op when it is not placed)."""
+        j = self.place[i]
+        if j >= 0:
+            self.mem_used[j] -= self.mem[i]
+            self.comp_used[j] -= self.comp[i]
+            self.place[i] = -1
+            self._show(i, self.base[i])
+
+    def move(self, k: int, dest: int):
+        """Move the placed block k to dest."""
+        src = self.place[k]
+        self.place[k] = dest
+        self.mem_used[src] -= self.mem[k]
+        self.comp_used[src] -= self.comp[k]
+        self.mem_used[dest] += self.mem[k]
+        self.comp_used[dest] += self.comp[k]
+        self._show(k, dest)
+
+    def on(self, j: int) -> np.ndarray:
+        """Blocks placed on j, in block order."""
+        return np.flatnonzero(self.place == j)
 
 
 class ResourceAwareAssigner:
@@ -75,35 +130,20 @@ class ResourceAwareAssigner:
         # line 4: descending by memory demand (compute tie-break)
         order = sorted(range(B), key=lambda i: (-mem[i], -comp[i]))
 
-        place = np.full(B, -1, dtype=int)
-        mem_used = np.zeros(V)
-        comp_used = np.zeros(V)
+        st = _Tentative(prev, mem, comp, V)
         delay: Optional[LayeredTotalDelay] = None   # built on the first tie
 
         def assigned_ok(j) -> bool:
             return (net.is_active(j) and
-                    mem_used[j] <= net.mem_avail[j] and
-                    comp_used[j] <= net.compute_avail[j] * self.deadline)
+                    st.mem_used[j] <= net.mem_avail[j] and
+                    st.comp_used[j] <= net.compute_avail[j] * self.deadline)
 
-        def do_place(i, j):
-            place[i] = j
-            mem_used[j] += mem[i]
-            comp_used[j] += comp[i]
-
-        def undo_place(i):
-            j = place[i]
-            if j >= 0:
-                mem_used[j] -= mem[i]
-                comp_used[j] -= comp[i]
-                place[i] = -1
-
-        def device_order(i: int) -> tuple[List[int], np.ndarray]:
+        def device_order(i: int) -> tuple[List[int], List[float]]:
             """Returns (candidate order, raw load-aware scores).  The same
             load-aware scores drive both the sort and the caller's
             feasibility check — one scoring convention (hysteresis and the
             objective tie-break only perturb the *order*, never the raw
             scores the feasibility test reads)."""
-            bl = self.blocks[i]
             # Load-aware scores: free memory and queued compute on j are
             # subtracted/added (Algorithm 1 line 10's aggregate check, folded
             # into the score so the argmin spreads load instead of stacking
@@ -112,15 +152,14 @@ class ResourceAwareAssigner:
             # knowledge: this round's tentative placement overlaid on prev
             # (-1 = still unknown), so even the first interval sees the
             # links its already-placed proj/ffn/neighbor-layer blocks use.
-            view = place if prev is None else np.where(place >= 0, place, prev)
-            raw = np.array([
-                score(bl, j, self.blocks, view, self.cost, net, tau,
-                      deadline=self.deadline, mem_used=mem_used,
-                      compute_used=comp_used) for j in range(V)])
-            scores = raw.copy()
+            raw = block_scores(
+                self.blocks[i], self.blocks, st.view, self.cost, net, tau,
+                deadline=self.deadline, mem_used=st.mem_used,
+                compute_used=st.comp_used)
+            scores = list(raw)
             if prev is not None:
                 scores[prev[i]] *= self.hysteresis  # anti-thrash stickiness
-            order = list(np.argsort(scores, kind="stable"))
+            order = sorted(range(V), key=scores.__getitem__)   # stable
             if self.objective_tiebreak and prev is not None:
                 best = scores[order[0]]
                 ties = [j for j in order
@@ -128,15 +167,17 @@ class ResourceAwareAssigner:
                 if len(ties) > 1:
                     # marginal D_T + D_mig of i on each tied device, with
                     # unplaced blocks still on prev: only the layers block
-                    # i touches are repriced (whole-graph total_delay per
-                    # tie is quadratic in blocks and ran out t_max at 48
-                    # layers)
+                    # i touches are repriced, all ties in one call
                     nonlocal delay
                     if delay is None:
                         delay = LayeredTotalDelay(prev, self.blocks,
                                                   self.cost, net, tau)
-                    delay.update(view)
-                    ties.sort(key=lambda j: delay.total_with(i, j))
+                    if st.changed:
+                        delay.update(st.view, np.array(st.changed))
+                        st.changed.clear()
+                    keys = delay.totals_with(i, ties)
+                    ties = [ties[t] for t in sorted(range(len(ties)),
+                                                    key=keys.__getitem__)]
                     rest = [j for j in order if j not in ties]
                     order = ties + rest
             return order, raw
@@ -156,7 +197,7 @@ class ResourceAwareAssigner:
                     # one (the old load-blind `break` here silently skipped
                     # such devices).
                     continue
-                do_place(i, j)
+                st.put(i, j)
                 if assigned_ok(j):
                     placed = True
                     if prev is not None and prev[i] != j:
@@ -165,10 +206,9 @@ class ResourceAwareAssigner:
                             return self._fail(stats, t0)
                     break
                 # line 10-14: revert + try to free capacity
-                undo_place(i)
-                if self._resolve_overload(i, j, place, mem_used, comp_used,
-                                          mem, comp, net, stats, U):
-                    do_place(i, j)
+                st.take(i)
+                if self._resolve_overload(i, j, st, net, stats, U):
+                    st.put(i, j)
                     placed = True
                     break
                 stats.migrations += 1
@@ -176,9 +216,7 @@ class ResourceAwareAssigner:
                     return self._fail(stats, t0)
             if not placed:
                 # lines 18-21: no device feasible for i alone
-                if not self._resolve_overload(i, None, place, mem_used,
-                                              comp_used, mem, comp, net,
-                                              stats, U):
+                if not self._resolve_overload(i, None, st, net, stats, U):
                     return self._fail(stats, t0)
                 # retry on the freshly freed device set (permissive: the
                 # desperate path takes any ACTIVE device the aggregate
@@ -188,27 +226,26 @@ class ResourceAwareAssigner:
                 for j in cand:
                     if not net.is_active(j):
                         continue
-                    do_place(i, j)
+                    st.put(i, j)
                     if assigned_ok(j):
                         placed = True
                         break
-                    undo_place(i)
+                    st.take(i)
                 if not placed:
                     return self._fail(stats, t0)
 
         # lines 23-29 ------------------------------------------------------
         guard = 0
-        while not self._all_ok(place, mem_used, comp_used, net):
+        while not self._all_ok(st, net):
             if guard > U or time.monotonic() - t0 > self.t_max:
                 return self._fail(stats, t0)
-            if not self._backtrack(place, mem_used, comp_used, mem, comp,
-                                   net, stats):
+            if not self._backtrack(st, net, stats):
                 return self._fail(stats, t0)
             stats.backtracks += 1
             guard += 1
 
         stats.elapsed = time.monotonic() - t0
-        return place, stats
+        return st.place, stats
 
     # ------------------------------------------------------------- helpers
     def _fail(self, stats: AlgoStats, t0) -> tuple[None, AlgoStats]:
@@ -216,97 +253,83 @@ class ResourceAwareAssigner:
         stats.elapsed = time.monotonic() - t0
         return INFEASIBLE, stats
 
-    def _all_ok(self, place, mem_used, comp_used, net) -> bool:
-        if (place < 0).any():
+    def _all_ok(self, st: _Tentative, net) -> bool:
+        if (st.place < 0).any():
             return False
-        return bool(np.all(mem_used <= net.mem_avail + 1e-9) and
-                    np.all(comp_used <= net.compute_avail * self.deadline
+        return bool(np.all(st.mem_used <= net.mem_avail + 1e-9) and
+                    np.all(st.comp_used <= net.compute_avail * self.deadline
                            + 1e-9))
 
-    def _resolve_overload(self, i: int, target: Optional[int], place,
-                          mem_used, comp_used, mem, comp, net,
-                          stats: AlgoStats, U: int) -> bool:
+    def _resolve_overload(self, i: int, target: Optional[int],
+                          st: _Tentative, net, stats: AlgoStats,
+                          U: int) -> bool:
         """ResolveResourceOverload (§IV.B1): migrate already-placed blocks
         away from the overloaded device (smallest sufficient set, smallest
         blocks first) onto devices with headroom."""
-        need_mem = mem[i]
-        need_comp = comp[i]
+        need_mem = st.mem[i]
+        need_comp = st.comp[i]
         devices = [target] if target is not None else \
-            list(np.argsort(mem_used))  # try least-loaded device first
+            list(np.argsort(st.mem_used))  # try least-loaded device first
         for j in devices:
             if j is None or not net.is_active(j):
                 continue
-            movable = [k for k in range(len(place)) if place[k] == j and k != i]
-            movable.sort(key=lambda k: mem[k])
+            movable = [k for k in st.on(j).tolist() if k != i]
+            movable.sort(key=lambda k: st.mem[k])
             moved: List[tuple[int, int]] = []
             for k in movable:
-                if (mem_used[j] + need_mem <= net.mem_avail[j] and
-                        comp_used[j] + need_comp
+                if (st.mem_used[j] + need_mem <= net.mem_avail[j] and
+                        st.comp_used[j] + need_comp
                         <= net.compute_avail[j] * self.deadline):
                     break
-                dest = self._find_room(k, j, place, mem_used, comp_used,
-                                       mem, comp, net)
+                dest = self._find_room(k, j, st, net)
                 if dest is None:
                     continue
-                place[k] = dest
-                mem_used[j] -= mem[k]
-                comp_used[j] -= comp[k]
-                mem_used[dest] += mem[k]
-                comp_used[dest] += comp[k]
+                st.move(k, dest)
                 moved.append((k, j))
                 stats.migrations += 1
                 if stats.migrations > U:
                     return False
-            if (mem_used[j] + need_mem <= net.mem_avail[j] and
-                    comp_used[j] + need_comp
+            if (st.mem_used[j] + need_mem <= net.mem_avail[j] and
+                    st.comp_used[j] + need_comp
                     <= net.compute_avail[j] * self.deadline):
                 return True
             # undo this device's moves and try the next candidate
             for k, src in reversed(moved):
-                dest = place[k]
-                place[k] = src
-                mem_used[dest] -= mem[k]
-                comp_used[dest] -= comp[k]
-                mem_used[src] += mem[k]
-                comp_used[src] += comp[k]
+                st.move(k, src)
         return False
 
-    def _find_room(self, k: int, avoid: int, place, mem_used, comp_used,
-                   mem, comp, net) -> Optional[int]:
+    def _find_room(self, k: int, avoid: int, st: _Tentative,
+                   net) -> Optional[int]:
         best, best_slack = None, -np.inf
+        mem, comp = st.mem, st.comp
         for j in net.active_ids:
             if j == avoid:
                 continue
-            if (mem_used[j] + mem[k] <= net.mem_avail[j] and
-                    comp_used[j] + comp[k]
+            if (st.mem_used[j] + mem[k] <= net.mem_avail[j] and
+                    st.comp_used[j] + comp[k]
                     <= net.compute_avail[j] * self.deadline):
-                slack = (net.mem_avail[j] - mem_used[j] - mem[k]) \
+                slack = (net.mem_avail[j] - st.mem_used[j] - mem[k]) \
                     / net.mem_avail[j]
                 if slack > best_slack:
                     best, best_slack = j, slack
         return best
 
-    def _backtrack(self, place, mem_used, comp_used, mem, comp, net,
-                   stats: AlgoStats) -> bool:
+    def _backtrack(self, st: _Tentative, net, stats: AlgoStats) -> bool:
         """BacktrackForResourceViolations (§IV.B2): remove a minimal set of
         blocks from each violated device (largest first) and re-place them."""
         progressed = False
         for j in range(net.n_devices):
-            while (mem_used[j] > net.mem_avail[j] + 1e-9 or
-                   comp_used[j] > net.compute_avail[j] * self.deadline + 1e-9):
-                on_j = [k for k in range(len(place)) if place[k] == j]
+            while (st.mem_used[j] > net.mem_avail[j] + 1e-9 or
+                   st.comp_used[j] > net.compute_avail[j] * self.deadline
+                   + 1e-9):
+                on_j = st.on(j).tolist()
                 if not on_j:
                     break
-                k = max(on_j, key=lambda t: mem[t])
-                dest = self._find_room(k, j, place, mem_used, comp_used,
-                                       mem, comp, net)
+                k = max(on_j, key=lambda t: st.mem[t])
+                dest = self._find_room(k, j, st, net)
                 if dest is None:
                     return False
-                place[k] = dest
-                mem_used[j] -= mem[k]
-                comp_used[j] -= comp[k]
-                mem_used[dest] += mem[k]
-                comp_used[dest] += comp[k]
+                st.move(k, dest)
                 progressed = True
         return progressed
 

@@ -62,6 +62,7 @@ D_T — which also makes D_pipe(K) ≤ D_T an invariant for every K ≥ 1.
 """
 from __future__ import annotations
 
+import bisect
 from typing import Optional, Sequence
 
 import numpy as np
@@ -102,34 +103,53 @@ def _expert_stage(g, l, place, cost, tau):
     return agg
 
 
+def _head_in(net: DeviceNetwork, sources, w_in: float, j: int) -> float:
+    """Inbound time of a head on device j: each source's share of the
+    activation over its link."""
+    return sum(fr * w_in / _rate(net, s, j) for s, fr in sources)
+
+
+def _head_chain(net: DeviceNetwork, j: int, n: int, on: float,
+                t_in: float, d_proj: int, w_out: float) -> float:
+    """Chain time of every head on device j, which holds n heads of summed
+    compute ``on`` (co-located heads run sequentially): inbound + compute
+    + the serialized head->proj volume (whole bytes, so count x volume is
+    exact)."""
+    return t_in + _cdiv(on, net.compute_avail[j]) + \
+        n * w_out / _rate(net, j, d_proj)
+
+
+def _worst(n_on: list, chain: list) -> float:
+    """The head stage's time: the max chain over devices that host heads."""
+    worst = 0.0
+    for n, t in zip(n_on, chain):
+        if n:
+            worst = max(worst, t)
+    return worst
+
+
 def _layer_terms(g, l: int, place, sources, w_in: float, comp,
                  cost: CostModel, net: DeviceNetwork, tau: int,
                  strict_eq6: bool) -> tuple:
     """Layer l's additive D_T terms, in the order ``inference_delay`` sums
-    them, and the (device, load fraction) sources it hands layer l+1's
-    heads.  ``sources``/``w_in`` are what layer l's heads receive;
-    ``comp`` is ``cost.compute_vector(blocks, tau)``."""
+    them, the (device, load fraction) sources it hands layer l+1's heads,
+    and its head stage: per device, the heads it holds and their chain
+    time.  ``sources``/``w_in`` are what layer l's heads receive; ``comp``
+    is ``cost.compute_vector(blocks, tau)``."""
     d_proj = int(place[g.proj[l].index])
     hidx = g.head_index[l]
     head_dev = place[hidx]
-    # per-device summed head compute (sequential sharing: bincount adds in
-    # head order) and per-link summed head->proj volume (serialized
-    # sharing; whole bytes, so count x volume is exact)
-    head_compute_on = np.bincount(head_dev, weights=comp[hidx],
-                                  minlength=net.n_devices)
-    n_on = np.bincount(head_dev, minlength=net.n_devices)
-    vol_to_proj = n_on * cost.head_to_proj_bytes(tau)
-
-    # every head on device j has the same chain time: take the max over
-    # the devices that host heads
-    worst = 0.0
-    for j in np.flatnonzero(n_on).tolist():
-        t_in = sum(fr * w_in / _rate(net, s, j) for s, fr in sources)
-        t_proc = _cdiv(head_compute_on[j], net.compute_avail[j])
-        t_out = vol_to_proj[j] / _rate(net, j, d_proj)
-        worst = max(worst, t_in + t_proc + t_out)
-
-    terms = [worst]
+    # per-device head count and summed head compute (sequential sharing:
+    # bincount adds in head order)
+    n_on = np.bincount(head_dev, minlength=net.n_devices).tolist()
+    on = np.bincount(head_dev, weights=comp[hidx],
+                     minlength=net.n_devices).tolist()
+    w_out = cost.head_to_proj_bytes(tau)
+    chain = [_head_chain(net, j, n, on[j], _head_in(net, sources, w_in, j),
+                         d_proj, w_out) if n else 0.0
+             for j, n in enumerate(n_on)]
+    heads = (n_on, chain)
+    terms = [_worst(n_on, chain)]
     if not strict_eq6:
         terms.append(_cdiv(comp[g.proj[l].index], net.compute_avail[d_proj]))
     if g.ffn[l] is not None:
@@ -138,7 +158,7 @@ def _layer_terms(g, l: int, place, sources, w_in: float, comp,
         if not strict_eq6:
             terms.append(_cdiv(comp[g.ffn[l].index],
                                net.compute_avail[d_ffn]))
-        return terms, [(d_ffn, 1.0)]
+        return terms, [(d_ffn, 1.0)], heads
     # expert stage: router fan-out (load-fraction-scaled proj->expert
     # transfer) + per-device expert compute, run in parallel across expert
     # devices -> the stage is the slowest device's (transfer, compute)
@@ -157,7 +177,7 @@ def _layer_terms(g, l: int, place, sources, w_in: float, comp,
     terms.append(stage_t)
     if not strict_eq6:
         terms.append(stage_c)
-    return terms, [(d, agg[d][0]) for d in sorted(agg)]
+    return terms, [(d, agg[d][0]) for d in sorted(agg)], heads
 
 
 def inference_delay(place: np.ndarray, blocks: Sequence[Block],
@@ -174,8 +194,8 @@ def inference_delay(place: np.ndarray, blocks: Sequence[Block],
     sources = [(net.controller, 1.0)]
     w_in = cost.input_bytes(tau)
     for l in range(g.n_layers):
-        terms, sources = _layer_terms(g, l, place, sources, w_in, comp,
-                                      cost, net, tau, strict_eq6)
+        terms, sources, _ = _layer_terms(g, l, place, sources, w_in, comp,
+                                         cost, net, tau, strict_eq6)
         for t in terms:
             total += t
         w_in = cost.interlayer_bytes(tau)
@@ -336,37 +356,72 @@ def total_delay(prev: Optional[np.ndarray], place: np.ndarray,
 class LayeredTotalDelay:
     """``total_delay(prev, place, ...)`` kept per layer, so that moving one
     block reprices only the layers it touches: its own, and the next one
-    when the block feeds the next layer's heads (ffn / expert).  The terms
-    are summed in ``inference_delay``'s order, so every value equals
-    ``total_delay`` bit for bit — on a 48-layer graph one repricing costs
-    two layers instead of the whole graph."""
+    when the block feeds the next layer's heads (ffn / expert).
+
+    The adopted terms are kept flat, in ``inference_delay``'s order, with
+    their prefix sums, and so is the migration vector.  Pricing a candidate
+    continues the adopted prefix before the first term it changes with the
+    same sequential additions (``np.add.accumulate`` is strictly left to
+    right; a migration entry of 0.0 adds nothing, so only moved blocks are
+    summed), so every value equals ``total_delay`` bit for bit.  A head
+    moves only its own layer's head-chain term: its proj / ffn terms and
+    the sources it hands layer l+1 do not depend on where heads sit."""
 
     def __init__(self, prev: np.ndarray, blocks: Sequence[Block],
                  cost: CostModel, net: DeviceNetwork, tau: int):
-        self.g = graph_of(blocks)
+        g = self.g = graph_of(blocks)
         self.cost, self.net, self.tau = cost, net, tau
         self.prev = np.asarray(prev, dtype=int)
-        self.comp = cost.compute_vector(self.g.blocks, tau)
-        self.mem_prev = cost.memory_vector(self.g.blocks, tau - 1)
-        self.layer = np.array([b.layer for b in self.g.blocks])
+        self.comp = cost.compute_vector(g.blocks, tau)
+        self.mem_prev = cost.memory_vector(g.blocks, tau - 1)
+        self.layer = np.array([b.layer for b in g.blocks])
         self.feeds_next = np.array([b.kind in (FFN, EXPERT)
-                                    for b in self.g.blocks])
+                                    for b in g.blocks])
+        # position of each head in its layer's ``head_index``; -1 otherwise
+        self.head_pos = np.full(len(g.blocks), -1)
+        for hidx in g.head_index:
+            self.head_pos[hidx] = np.arange(len(hidx))
+        self.w_out = cost.head_to_proj_bytes(tau)
+        self.head_comp = [self.comp[hidx].tolist() for hidx in g.head_index]
         self.place = self.prev.copy()
-        self.mig = np.zeros(len(self.g.blocks))   # prev == place: no moves
-        self.terms: list = [None] * self.g.n_layers
-        self.out: list = [None] * self.g.n_layers
-        for l in range(self.g.n_layers):
-            self.terms[l], self.out[l] = self._layer(l, self.place,
-                                                     self._sources(l))
+        self.mig = np.zeros(len(g.blocks))   # prev == place: no moves
+        self.out: list = [None] * g.n_layers
+        # per layer: the adopted head stage (``_layer_terms``' third value)
+        # and, once a head of the layer is priced, each device's t_in
+        self.heads: list = [None] * g.n_layers
+        self.head_in: list = [None] * g.n_layers
+        terms = [self._reprice(l) for l in range(g.n_layers)]
+        self.start = np.cumsum([0] + [len(t) for t in terms])
+        self.flat = np.array([t for ts in terms for t in ts], dtype=float)
+        self._prefix()
 
     def _sources(self, l: int):
         return [(self.net.controller, 1.0)] if l == 0 else self.out[l - 1]
 
-    def _layer(self, l: int, place, sources):
-        w_in = self.cost.input_bytes(self.tau) if l == 0 \
+    def _w_in(self, l: int) -> float:
+        return self.cost.input_bytes(self.tau) if l == 0 \
             else self.cost.interlayer_bytes(self.tau)
-        return _layer_terms(self.g, l, place, sources, w_in, self.comp,
-                            self.cost, self.net, self.tau, False)
+
+    def _layer(self, l: int, place, sources):
+        return _layer_terms(self.g, l, place, sources, self._w_in(l),
+                            self.comp, self.cost, self.net, self.tau, False)
+
+    def _reprice(self, l: int) -> list:
+        """Reprice layer l under the adopted placement; returns its terms."""
+        terms, self.out[l], self.heads[l] = self._layer(l, self.place,
+                                                        self._sources(l))
+        self.head_in[l] = None
+        return terms
+
+    def _prefix(self):
+        # sums of the adopted terms before each position (leading 0.0),
+        # and of the nonzero migration entries in block order
+        self.pre = np.concatenate(([0.0], np.add.accumulate(self.flat)))
+        at = np.flatnonzero(self.mig)
+        self.mig_at = at.tolist()
+        self.mig_val = self.mig[at]
+        self.mig_pre = np.concatenate(([0.0],
+                                       np.add.accumulate(self.mig_val)))
 
     def _mig_term(self, i: int, k: int) -> float:
         j = int(self.prev[i])
@@ -377,50 +432,122 @@ class LayeredTotalDelay:
         return [l, l + 1] if self.feeds_next[i] and l + 1 < self.g.n_layers \
             else [l]
 
-    def update(self, place: np.ndarray):
+    def update(self, place: np.ndarray, moved: Optional[np.ndarray] = None):
         """Adopt ``place`` (complete, no -1), repricing only the layers
-        whose blocks moved since the last adopted placement."""
-        moved = np.flatnonzero(place != self.place)
+        whose blocks moved since the last adopted placement.  ``moved``,
+        when given, holds every index that may differ (more is fine)."""
+        if moved is None:
+            moved = np.flatnonzero(place != self.place)
+        else:
+            moved = np.unique(moved)
+            moved = moved[place[moved] != self.place[moved]]
         if not moved.size:
             return
-        self.place = np.array(place, dtype=int)
+        self.place[moved] = place[moved]
         dirty = set()
-        for i in moved:
-            self.mig[i] = self._mig_term(int(i), int(place[i]))
-            dirty.update(self._touched(int(i)))
+        for i in moved.tolist():
+            self.mig[i] = self._mig_term(i, int(self.place[i]))
+            dirty.update(self._touched(i))
         for l in sorted(dirty):      # ascending: layer l reads out[l - 1]
-            self.terms[l], self.out[l] = self._layer(l, self.place,
-                                                     self._sources(l))
+            self.flat[self.start[l]:self.start[l + 1]] = self._reprice(l)
+        self._prefix()
+
+    def _head_worsts(self, i: int, js: list) -> list:
+        """Layer l's head-stage term (``_layer_terms``' first) with head i
+        moved to each of ``js``.  Only the device it leaves and the one it
+        joins change; their compute is re-summed in head order, as
+        bincount does."""
+        l, pos = int(self.layer[i]), int(self.head_pos[i])
+        devs = self.place[self.g.head_index[l]].tolist()
+        comp = self.head_comp[l]
+        n_on, chain = self.heads[l]
+        net = self.net
+        if self.head_in[l] is None:
+            sources, w_in = self._sources(l), self._w_in(l)
+            self.head_in[l] = [_head_in(net, sources, w_in, j)
+                               for j in range(net.n_devices)]
+        t_in = self.head_in[l]
+        d_proj = int(self.place[self.g.proj[l].index])
+        a = devs[pos]
+        # each device's head compute with head i joining it, and a's
+        # without head i
+        joined = [0.0] * len(n_on)
+        left = 0.0
+        for p, d in enumerate(devs):
+            if p == pos:
+                joined = [on + comp[p] for on in joined]
+                continue
+            joined[d] += comp[p]
+            if d == a:
+                left += comp[p]
+        n, c = list(n_on), list(chain)
+        n[a] -= 1
+        c[a] = _head_chain(net, a, n[a], left, t_in[a], d_proj, self.w_out)
+        out = []
+        for j in js:
+            n[j] += 1
+            c_j, c[j] = c[j], _head_chain(net, j, n[j], joined[j], t_in[j],
+                                          d_proj, self.w_out)
+            out.append(_worst(n, c))
+            n[j] -= 1
+            c[j] = c_j
+        return out
+
+    def totals_with(self, i: int, js) -> list:
+        """``total_delay`` of the adopted placement with block i on each
+        device of ``js``, bit for bit."""
+        total = self.total()                 # i stays where it is
+        moves = [int(j) for j in js if j != self.place[i]]
+        if not moves:
+            return [total] * len(js)
+        ls = self._touched(i)
+        if self.head_pos[i] >= 0:
+            new = [[t] for t in self._head_worsts(i, moves)]
+        else:
+            new = self._layers_with(i, moves, ls)
+        a, n = int(self.start[ls[0]]), len(new[0])
+        tail = self.flat[a + n:]
+        # the migration vector with entry i replaced, its zeros skipped
+        p = bisect.bisect_left(self.mig_at, i)
+        q = p + (p < len(self.mig_at) and self.mig_at[p] == i)
+        mig_tail = self.mig_val[q:]
+        # one row per sum, both continued from their prefix in one
+        # sequential pass (trailing zeros add nothing)
+        K = len(moves)
+        rows = np.zeros((2 * K, 2 + max(n + len(tail), len(mig_tail))))
+        rows[:K, 0] = self.pre[a]
+        rows[:K, 1:1 + n] = new
+        rows[:K, 1 + n:1 + n + len(tail)] = tail
+        rows[K:, 0] = self.mig_pre[p]
+        rows[K:, 1] = [self._mig_term(i, j) for j in moves]
+        rows[K:, 2:2 + len(mig_tail)] = mig_tail
+        sums = np.add.accumulate(rows, axis=1)[:, -1]
+        priced = iter((sums[:K] + sums[K:]).tolist())
+        return [total if j == self.place[i] else next(priced) for j in js]
+
+    def _layers_with(self, i: int, js: list, ls: list) -> list:
+        """The terms of layers ``ls`` with block i on each of ``js``."""
+        old = self.place[i]
+        new = []
+        try:
+            for j in js:
+                self.place[i] = j
+                src, terms = self._sources(ls[0]), []
+                for l in ls:
+                    t, src, _ = self._layer(l, self.place, src)
+                    terms += t
+                new.append(terms)
+        finally:
+            self.place[i] = old
+        return new
 
     def total_with(self, i: int, j: int) -> float:
         """``total_delay`` of the adopted placement with block i on j."""
-        old = self.place[i]
-        self.place[i] = j
-        try:
-            subst = {}
-            for l in self._touched(i):
-                src = subst[l - 1][1] if l - 1 in subst else self._sources(l)
-                subst[l] = self._layer(l, self.place, src)
-        finally:
-            self.place[i] = old
-        mig = self.mig.copy()
-        mig[i] = self._mig_term(i, j)
-        return self._sum([subst[l][0] if l in subst else self.terms[l]
-                          for l in range(self.g.n_layers)], mig)
+        return self.totals_with(i, [j])[0]
 
     def total(self) -> float:
         """``total_delay`` of the adopted placement."""
-        return self._sum(self.terms, self.mig)
-
-    @staticmethod
-    def _sum(layer_terms, mig) -> float:
-        total = 0.0
-        for terms in layer_terms:
-            for t in terms:
-                total += t
-        # add.accumulate is a sequential left-to-right sum: the same
-        # association as migration_delay's loop (unmoved blocks add 0.0)
-        return float(total) + float(np.add.accumulate(mig)[-1])
+        return float(self.pre[-1]) + float(self.mig_pre[-1])
 
 
 def pipelined_total_delay(prev: Optional[np.ndarray], place: np.ndarray,
@@ -485,7 +612,7 @@ def revert_unpaying_migrations(prev: Optional[np.ndarray],
             current[i] = dst
             cur_val = val
             if k == 1:
-                delay.update(current)
+                delay.update(current, [i])
         else:
             use[src] += mem[i]
             use[dst] -= mem[i]

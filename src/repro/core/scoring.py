@@ -22,7 +22,7 @@ The paper leaves two scalings implicit; we make them explicit and testable:
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -130,6 +130,105 @@ def score(block: Block, j: int, blocks: Sequence[Block],
         / comp_avail / deadline
     cf = comm_factor(block, j, blocks, prev_place, cost, net, tau, deadline)
     return float(max(mem_term, comp_term, cf))
+
+
+def _comm_times(block: Block, blocks: Sequence[Block],
+                prev_place: Optional[np.ndarray], cost: CostModel,
+                net: DeviceNetwork, tau: int) -> List[float]:
+    """``comm_factor``'s transfer time (before the deadline divide) on
+    every device, with the counterpart devices and byte volumes read once;
+    each device's arithmetic is ``comm_factor``'s, in its order."""
+    bw = net.bandwidth
+
+    def rate(a, b):
+        return np.inf if a == b else float(bw[a, b])
+
+    g = graph_of(blocks)
+    l = block.layer
+    devices = range(net.n_devices)
+
+    def dev(b: Block) -> int:
+        return -1 if prev_place is None else int(prev_place[b.index])
+
+    def fraction(b: Block) -> float:
+        return 1.0 if b.kind != EXPERT else cost.expert_load(b)
+
+    if block.kind == HEAD:
+        if l == 0:
+            ins = [(net.controller, cost.input_bytes(tau))]
+        else:
+            ins = [(dev(b), fraction(b) * cost.interlayer_bytes(tau))
+                   for b in g.out_blocks(l - 1) if dev(b) >= 0]
+        proj_dev = dev(g.proj[l])
+        w_out = cost.head_to_proj_bytes(tau)
+        out = []
+        for j in devices:
+            t = 0.0
+            for src, w in ins:
+                t += w / rate(src, j)
+            if proj_dev >= 0:
+                t += w_out / rate(j, proj_dev)
+            out.append(t)
+        return out
+    if block.kind == PROJ:
+        head_devs = set(d for d in (dev(h) for h in g.heads[l]) if d >= 0)
+        t_in = cost.head_to_proj_bytes(tau) * cost.n_heads
+        outs = [(dev(b), fraction(b) * cost.proj_to_ffn_bytes(tau))
+                for b in g.out_blocks(l) if dev(b) >= 0]
+        out = []
+        for j in devices:
+            t = 0.0
+            if head_devs:
+                t = t_in / min(rate(h_dev, j) for h_dev in head_devs)
+            for out_dev, w in outs:
+                t = max(t, w / rate(j, out_dev))
+            out.append(t)
+        return out
+    # ffn / expert: inbound from proj(l), outbound to layer l+1's heads
+    # (an ffn carries the whole activation: 1.0 x bytes is exact)
+    fr = fraction(block)
+    w_in = fr * cost.proj_to_ffn_bytes(tau)
+    w_out = fr * cost.interlayer_bytes(tau)
+    proj_dev = dev(g.proj[l])
+    next_devs = set() if l + 1 >= g.n_layers else \
+        set(d for d in (dev(h) for h in g.heads[l + 1]) if d >= 0)
+    out = []
+    for j in devices:
+        t = 0.0
+        if proj_dev >= 0:
+            t = w_in / rate(proj_dev, j)
+        if next_devs:
+            t = max(t, w_out / min(rate(j, d) for d in next_devs))
+        out.append(t)
+    return out
+
+
+def block_scores(block: Block, blocks: Sequence[Block],
+                 prev_place: Optional[np.ndarray], cost: CostModel,
+                 net: DeviceNetwork, tau: int, *, deadline: float = 5.0,
+                 mem_used: Optional[np.ndarray] = None,
+                 compute_used: Optional[np.ndarray] = None) -> List[float]:
+    """``[score(block, j, ...) for j in range(V)]``, bit for bit: what does
+    not depend on the device (the block's memory and compute, its byte
+    volumes, its counterparts' devices) is read once."""
+    V = net.n_devices
+    comm = _comm_times(block, blocks, prev_place, cost, net, tau)
+    m_i, b_i = cost.memory(block, tau), cost.compute(block, tau)
+    mem_used = [0.0] * V if mem_used is None else mem_used.tolist()
+    compute_used = [0.0] * V if compute_used is None \
+        else compute_used.tolist()
+    out = []
+    for active, mem_avail, comp_avail, m_used, c_used, t in zip(
+            net.active.tolist(), net.mem_avail.tolist(),
+            net.compute_avail.tolist(), mem_used, compute_used, comm):
+        mem_cap = mem_avail - m_used
+        if not active or mem_cap <= 0 or comp_avail <= 0:
+            out.append(np.inf)
+            continue
+        out.append(max(m_i / mem_cap,
+                       (b_i + c_used) / comp_avail / deadline,
+                       t / deadline))
+    return out
 
 
 def score_matrix(blocks: Sequence[Block], prev_place: Optional[np.ndarray],
