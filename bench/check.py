@@ -19,7 +19,9 @@ bfloat16 served, and reads, at the same positions, the gap of the token
 that forward puts first: the reference put in the program's place.
 
 Weights are regenerated from the seed one layer at a time, so this runs
-after the engine and its state have been freed.
+after the engine and its state have been freed.  Each layer is run with
+its kind of attention (``bench/work.py``'s ``layer_kinds``), one compiled
+step per kind and stream.
 """
 from __future__ import annotations
 
@@ -78,6 +80,7 @@ def gaps(conf: dict, seed: int, seqs: list, *, quant=None,
     import jax.numpy as jnp
 
     from bench import weights as W
+    from bench.work import layer_kinds
 
     ref = _reference_module(conf["reference"])
     spec = conf["model"]
@@ -95,14 +98,16 @@ def gaps(conf: dict, seed: int, seqs: list, *, quant=None,
         layer_w = jax.jit(lambda r, l: W.layer_weights(W.layer_key(r, l),
                                                        spec))
         outer = jax.jit(lambda r: W.outer_weights(W.outer_key(r), spec))(root)
-        step = {q: jax.jit(lambda p, x, q=q: ref.layer(spec, p, x, quant=q))
-                for q in streams}
+        kinds = layer_kinds(spec)
+        step = {(q, kd): jax.jit(lambda p, x, q=q, kd=kd: ref.layer(
+                    spec, p, x, quant=q, kind=kd))
+                for q in streams for kd in set(kinds)}
         xs = {q: [ref.embed(outer, jnp.asarray(t)) for t in toks]
               for q in streams}
-        for l in range(spec["n_layers"]):
+        for l, kd in enumerate(kinds):
             p = layer_w(root, l)
             for q in streams:
-                xs[q] = [step[q](p, x) for x in xs[q]]
+                xs[q] = [step[q, kd](p, x) for x in xs[q]]
             del p
 
         @jax.jit

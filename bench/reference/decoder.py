@@ -15,6 +15,18 @@ What it follows, per configuration file (``"model"``):
 - musicgen-large (arXiv 2306.05284): LayerNorm with bias, exact (erf) GELU
   MLP with biases, 32 heads with their own K and V.
 
+Two optional parts of the ``"model"`` block, for configurations that
+state them:
+
+- Routed experts (``n_experts``, ``experts_per_token``): the MLP is a set
+  of SwiGLU experts of width ``d_ff``; a float32 router's softmax over the
+  top-k logits weights the k experts a token is routed to (the same as
+  renormalising the top k of the full softmax).  The experts are looped
+  over, each computed for every token and weighted by its gate (0 for a
+  token not routed to it), so no (T, E, F) tensor is held.
+- Window layers (``layer_kinds`` of ``bench/work.py``): on a layer of kind
+  ``"window"`` key j is visible to query i iff i - sliding_window < j <= i.
+
 Departures from the published models, all shared with the program being
 checked: one token stream (MusicGen interleaves four EnCodec codebooks and
 adds T5 text conditioning by cross-attention; neither is modelled), and
@@ -28,7 +40,9 @@ MLP uses the tanh form of GELU; this uses the exact one.
 ``quant="int8"`` and ``quant="fp8"`` are the reference controls of
 ``bench/check.py``: every projection runs on int8 (W8A8) or float8 e4m3
 operands (weights scaled per output column, activations per token,
-symmetric), a step below the bfloat16 the configurations state.
+symmetric), a step below the bfloat16 the configurations state; so do the
+experts' gate, up and down projections.  The router stays float32 in both,
+as the program keeps it.
 """
 from __future__ import annotations
 
@@ -38,6 +52,9 @@ import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
+# keys of the "model" block this reference reads that are no field of the
+# program's ModelConfig (bench/run.py's ``model_config`` refuses others)
+SPEC_KEYS = ()
 QMAX = {"int8": 127.0, "fp8": 448.0}       # largest value of each format
 
 
@@ -98,8 +115,40 @@ def _to_reference_layout(spec, w, axis=-1):
     return jnp.take(w, src, axis=axis)
 
 
-def layer(spec, p, x, quant=None):
-    """One decoder layer over a whole sequence ``x`` (T, D), float32."""
+def _mask(T: int, window: int = 0):
+    """(T, T) True where query i sees key j: j <= i, and i - window < j
+    where ``window`` is set."""
+    i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    m = j <= i
+    return m & (j > i - window) if window else m
+
+
+def _swiglu(quant, h, wg, wu, wd):
+    u = jax.nn.silu(_mm(quant, h, wg, "td,df->tf")) \
+        * _mm(quant, h, wu, "td,df->tf")
+    return _mm(quant, u, wd, "tf,fd->td")
+
+
+def _experts(spec, m, h, quant):
+    """Routed SwiGLU experts: softmax over the top-k router logits, each
+    expert computed in turn for every token and weighted by its gate."""
+    logits = jnp.einsum("td,de->te", h, m["router"])
+    top, idx = jax.lax.top_k(logits, spec["experts_per_token"])
+    w = jax.nn.softmax(top, -1)
+    gates = jnp.sum(jax.nn.one_hot(idx, spec["n_experts"], dtype=F32)
+                    * w[..., None], axis=1)                         # (T, E)
+
+    def one(y, e):
+        wg, wu, wd, g = e
+        return y + g[:, None] * _swiglu(quant, h, wg, wu, wd), None
+    y, _ = jax.lax.scan(one, jnp.zeros_like(h),
+                        (m["w_gate"], m["w_up"], m["w_down"], gates.T))
+    return y
+
+
+def layer(spec, p, x, quant=None, kind="full"):
+    """One decoder layer over a whole sequence ``x`` (T, D), float32;
+    ``kind`` is the layer's kind of attention, "full" or "window"."""
     T = x.shape[0]
     H, K, dh = spec["n_heads"], spec["n_kv_heads"], spec["d_head"]
     a = jax.tree.map(lambda t: t.astype(F32), p["attn"])
@@ -117,16 +166,18 @@ def layer(spec, p, x, quant=None):
     k = jnp.repeat(k, H // K, axis=1)
     v = jnp.repeat(v, H // K, axis=1)
     s = jnp.einsum("thk,shk->hts", q, k) / math.sqrt(dh)
-    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None], s, -jnp.inf)
+    window = spec["sliding_window"] if kind == "window" else 0
+    s = jnp.where(_mask(T, window)[None], s, -jnp.inf)
     o = jnp.einsum("hts,shk->thk", jax.nn.softmax(s, -1), v)
     x = x + _mm(quant, o.reshape(T, H * dh), a["wo"].reshape(H * dh, -1),
                 "tf,fd->td")
     h = ln("ln2", x)
+    if "moe" in p:
+        m = jax.tree.map(lambda t: t.astype(F32), p["moe"])
+        return x + _experts(spec, m, h, quant)
     m = jax.tree.map(lambda t: t.astype(F32), p["mlp"])
     if spec["mlp"] == "swiglu":
-        u = jax.nn.silu(_mm(quant, h, m["w_gate"], "td,df->tf")) \
-            * _mm(quant, h, m["w_up"], "td,df->tf")
-        return x + _mm(quant, u, m["w_down"], "tf,fd->td")
+        return x + _swiglu(quant, h, m["w_gate"], m["w_up"], m["w_down"])
     u = jax.nn.gelu(_mm(quant, h, m["w_up"], "td,df->tf") + m["b_up"],
                     approximate=False)
     return x + _mm(quant, u, m["w_down"], "tf,fd->td") + m["b_down"]

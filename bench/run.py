@@ -12,9 +12,10 @@ and a traffic mix (``bench/traffic/``).  The run
    (paged KV, the Pallas decode kernel, the interval controller live);
 3. sets up: JAX's compilation cache in ``<checkout>/.jax_cache``, the
    slots filled with requests already in flight, and steps run until the
-   controller has applied a plan, so that every program the window runs
-   (prefill chunk, decode step, page mount, sampler, the migration's
-   permutes) has been compiled or loaded;
+   controller has applied a plan (of heads, or of a routed model's
+   experts), so that every program the window runs (prefill chunk, decode
+   step, page mount, sampler, the migration's permutes) has been compiled
+   or loaded;
 4. offers the mix's arrivals open-loop on the wall clock for ``--seconds``
    and drives ``ServingEngine.step``; every token is timestamped through
    the engine's ``token_sink``;
@@ -105,23 +106,44 @@ def metric_names(bench: dict, cell: dict, kind: str) -> List[str]:
 
 
 # ------------------------------------------------------------------ model
-SPEC_FIELDS = {"n_layers": "n_layers", "d_model": "d_model",
-               "n_heads": "n_heads", "n_kv_heads": "n_kv_heads",
-               "d_head": "d_head", "d_ff": "d_ff", "vocab_size": "vocab_size",
-               "norm_eps": "norm_eps", "qkv_bias": "qkv_bias",
-               "rope_theta": "rope_theta", "rope_fraction": "rope_fraction",
-               "dtype": "dtype"}
+REQUIRED = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_head", "d_ff",
+            "vocab_size", "norm", "norm_eps", "mlp", "qkv_bias", "rope_theta",
+            "rope_fraction", "dtype")
+RENAMED = {"norm": "norm_type", "mlp": "mlp_type"}   # spec key -> field
+ABSENT = {"n_experts": 0, "experts_per_token": 0, "sliding_window": 0}
 
 
 def model_config(conf: dict):
-    """The registry's config with the file's overrides; every size the
-    file states must be what the program will run."""
+    """The registry's config with the file's overrides.  Every key of the
+    file's ``"model"`` block that is a field of the program's
+    ``ModelConfig`` (``norm`` and ``mlp`` under their field names) must
+    hold what the program will run; ``layer_types`` must be the program's
+    kinds of attention; a key the reference alone reads is named in its
+    ``SPEC_KEYS``; any other key stops the run.  An absent key of
+    ``ABSENT`` means its value there."""
     from repro.configs import get_config
+
+    from bench import check as C
     cfg = get_config(conf["arch"]).with_overrides(**conf["overrides"])
     spec = conf["model"]
-    got = {k: getattr(cfg, a) for k, a in SPEC_FIELDS.items()}
-    got.update(norm=cfg.norm_type, mlp=cfg.mlp_type)
-    bad = {k: (spec[k], got[k]) for k in got if spec[k] != got[k]}
+    fields = {f.name for f in dataclasses.fields(cfg)}
+    ref_only = set(C._reference_module(conf["reference"]).SPEC_KEYS)
+    missing = [k for k in REQUIRED if k not in spec]
+    unknown = [k for k in spec if RENAMED.get(k, k) not in fields
+               and k != "layer_types" and k not in ref_only]
+    if missing or unknown:
+        raise SystemExit(f"{conf['arch']}: the model block lacks {missing} "
+                         f"and states {unknown}, which neither the program "
+                         f"nor the reference reads")
+    want = dict(ABSENT, **spec)
+    got = {k: getattr(cfg, RENAMED.get(k, k)) for k in want
+           if RENAMED.get(k, k) in fields}
+    got = {k: list(v) if isinstance(v, tuple) else v for k, v in got.items()}
+    if "layer_types" in want and "layer_types" not in fields:
+        # the program runs one window on every layer where it has one
+        got["layer_types"] = ["window" if cfg.sliding_window else "full"] \
+            * cfg.n_layers
+    bad = {k: (want[k], got[k]) for k in got if want[k] != got[k]}
     if bad or cfg.param_dtype != spec["dtype"] or cfg.tie_embeddings:
         raise SystemExit(f"{conf['arch']}: file and program disagree: {bad}")
     return cfg
@@ -301,7 +323,8 @@ def run_cell(root: Path, name: str, seed: int, seconds: float,
     supply = supply[n_fill:]
     while eng.queue:
         eng.step()
-    while not any(m["applied"] for m in eng.migration_log):
+    while not any(m["applied"] or m["expert_applied"]
+                  for m in eng.migration_log):
         if len(eng.migration_log) >= SETUP_MAX_INTERVALS:
             raise SystemExit(f"no plan applied in {SETUP_MAX_INTERVALS} "
                              f"controller intervals of set-up")
@@ -383,6 +406,7 @@ def run_cell(root: Path, name: str, seed: int, seconds: float,
         live_share=live,
         plan_s=[m["plan_s"] for m in log_w],
         applied_plans=sum(1 for m in log_w if m["applied"]),
+        expert_plans=sum(1 for m in log_w if m["expert_applied"]),
         queue_at_close=len(eng.queue))
     mem = [d.memory_stats() or {} for d in used]
     peak = max((m.get("peak_bytes_in_use", 0) for m in mem), default=0)
@@ -471,6 +495,7 @@ def run_cell(root: Path, name: str, seed: int, seconds: float,
     log(f"[bench] setup_s={run.setup_s!r} window_s={t_close - t0!r} "
         f"arrivals={attempted} finished_in_window={len(fin)} "
         f"applied_plans={run.counters['applied_plans']} "
+        f"expert_plans={run.counters['expert_plans']} "
         f"intervals={len(run.counters['plan_s'])} "
         f"compiles_in_window={len(in_window_compiles)} "
         f"{sorted(set(in_window_compiles))} hooked={hooked} "

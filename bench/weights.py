@@ -13,6 +13,12 @@ gives the same bits as drawing one layer alone (``tests/test_weights.py``).
 Biases and norm parameters are drawn too, not left at 0 and 1, so the
 comparison exercises QKV bias, the norms' scales and offsets, and the
 GELU MLP's biases.
+
+A spec with ``n_experts`` holds routed SwiGLU experts in place of the
+MLP, in ``models/moe.py``'s layout: ``moe`` = {``router`` (D, E) float32,
+``w_gate`` and ``w_up`` (E, D, F), ``w_down`` (E, F, D)}, with ``d_ff``
+one expert's width.  Those leaves have ids of their own (15..18), so a
+dense spec draws the same bits as before they existed.
 """
 from __future__ import annotations
 
@@ -51,7 +57,14 @@ def layer_weights(key, spec: dict) -> dict:
     if spec["norm"] == "layernorm":
         p["ln1_b"] = n(9, (D,), 0.1)
         p["ln2_b"] = n(10, (D,), 0.1)
-    if spec["mlp"] == "swiglu":
+    if spec.get("n_experts"):
+        E = spec["n_experts"]
+        p["moe"] = {"router": _normal(jax.random.fold_in(key, 15), (D, E),
+                                      D ** -0.5, jnp.float32),
+                    "w_gate": n(16, (E, D, F), D ** -0.5),
+                    "w_up": n(17, (E, D, F), D ** -0.5),
+                    "w_down": n(18, (E, F, D), F ** -0.5)}
+    elif spec["mlp"] == "swiglu":
         p["mlp"] = {"w_gate": n(11, (D, F), D ** -0.5),
                     "w_up": n(12, (D, F), D ** -0.5),
                     "w_down": n(13, (F, D), F ** -0.5)}
